@@ -4,7 +4,9 @@ Starts a gateway in a subprocess (as a real deployment would run
 ``scripts/gateway_serve.py``), then from this process: submits experiments
 across the three priority classes, watches one of them round-by-round over
 a second connection, fetches every result, and verifies one trajectory
-bit-for-bit against a local solo run — the DESIGN.md §14 contract.
+bit-for-bit against a local solo run — the DESIGN.md §14 contract.  Both
+processes run JAX on the host CPU: an accelerator belongs to one process at
+a time, and the bit-for-bit check needs both runs on the same platform.
 
     PYTHONPATH=src python examples/gateway_client.py
 """
@@ -15,6 +17,7 @@ import subprocess
 import sys
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
+os.environ["JAX_PLATFORMS"] = "cpu"  # before JAX is imported; see docstring
 
 
 def main() -> int:

@@ -273,6 +273,7 @@ class _ShardedSessionHandle(SessionHandle):
                 place=lambda arr, ref: jax.device_put(arr, ref.sharding),
             )
         self._state = state
+        self._data_devices = len(zs.sharding.device_set)
         self._round_fn = jax.jit(
             make_sharded_fednl_round(zs, cfg, mesh, aggregate=spec.aggregate)
         )
@@ -313,6 +314,9 @@ class _ShardedSessionHandle(SessionHandle):
             "extras": {
                 "devices": self._n_dev,
                 "aggregate": self._spec.aggregate,
+                # where the samples and the client shifts really sit
+                "data_devices": self._data_devices,
+                "state_devices": len(self._state.h_local.sharding.device_set),
             },
         }
 

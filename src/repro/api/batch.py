@@ -298,11 +298,10 @@ def _run_batched_group(
 
     n_dev = sweep_mesh_devices(n_batch)
     if n_dev > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = make_sweep_mesh(n_dev)
-        program = shard_map(
+        program = jax.shard_map(
             program,
             mesh=mesh,
             in_specs=tuple(P("sweep") for _ in operands),

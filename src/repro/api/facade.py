@@ -13,12 +13,11 @@ from __future__ import annotations
 
 from typing import Iterable
 
-import jax
-
 from repro.api.registry import Algorithm, Backend, get_algorithm, get_backend
 from repro.api.report import RunReport, SweepReport
 from repro.api.spec import ExperimentSpec
 from repro.api.sweep import SweepSpec
+from repro.runtime import configure_jax
 
 
 def check_spec(
@@ -76,8 +75,7 @@ def solve(spec: ExperimentSpec, z=None, x0=None) -> RunReport:
     zero initial iterate (local backend only; the wire protocols start every
     run from the INIT broadcast of the zero iterate).
     """
-    # FedNL is an FP64 algorithm end-to-end; idempotent when already enabled
-    jax.config.update("jax_enable_x64", True)
+    configure_jax()  # FP64 + compile cache; idempotent
     algo = get_algorithm(spec.algorithm)
     backend = get_backend(spec.backend)
     if backend.supports_sessions:
@@ -105,7 +103,7 @@ def solve_many(sweep: SweepSpec | Iterable[ExperimentSpec]) -> SweepReport:
     dispatched through a bounded worker pool; everything else falls back to
     per-spec ``solve()`` — each decision recorded in ``SweepReport.log``.
     """
-    jax.config.update("jax_enable_x64", True)
+    configure_jax()
     from repro.api.batch import run_sweep
 
     if isinstance(sweep, SweepSpec):

@@ -464,12 +464,11 @@ def open_session(
     checkpoint (only run control — rounds / tol / host — may differ;
     :meth:`ExperimentSpec.check_restore_from` rejects anything else loudly).
     """
-    import jax
-
     from repro.api.facade import check_spec
     from repro.api.registry import get_algorithm, get_backend
+    from repro.runtime import configure_jax
 
-    jax.config.update("jax_enable_x64", True)
+    configure_jax()
     state = None
     if restore is not None:
         state = restore if isinstance(restore, SessionState) else load_state(restore)

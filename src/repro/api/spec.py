@@ -156,8 +156,8 @@ class ExperimentSpec:
     # Hessian SYRK implementation (DESIGN.md §12): "fused" (default) routes
     # through kernels.ops.hessian_fused — bit-identical to "jnp" for
     # d <= 128, documented ulp drift above; "jnp" is the single-dot_general
-    # parity reference; "pallas" forces the Pallas wrapper (interpret mode
-    # off-TPU — the kernel-validation path, not a CPU hot path)
+    # parity reference; "pallas" forces the Pallas wrapper (compiled on TPU,
+    # f32/bf16 only; interpret mode elsewhere — the kernel-validation path)
     hessian: str = "fused"
     use_kernel: bool = False  # deprecated spelling of hessian="pallas"
     # line-search parameters (fednl-ls)

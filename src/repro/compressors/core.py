@@ -83,8 +83,9 @@ def topk(u: jax.Array, k: int, *, fused: bool = False) -> tuple[jax.Array, jax.A
     """Deterministic TopK by magnitude.  Contractive with delta = k/T.
 
     Routed through the fused selection entry point (`repro.kernels.ops`):
-    the Pallas kernel on TPU, the canonical jnp primitives (bit-identical by
-    the selection contract) everywhere else.  ``fused=True`` picks the
+    the Pallas kernel for f32/bf16 payloads on TPU, the canonical jnp
+    primitives (bit-identical by the selection contract) for f64 and
+    everywhere else.  ``fused=True`` picks the
     sort-free threshold-mask formulation the fused round maps per client
     (see `repro.compressors.select.topk_dense_masked`); outputs are
     bit-identical either way.

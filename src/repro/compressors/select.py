@@ -71,34 +71,78 @@ def threshold_keep_mask(keys: jax.Array, k: int) -> jax.Array:
     """Boolean keep-mask selecting the same set as ``top_k(keys, k)`` without
     a sort — the formulation the Pallas selection kernel runs.
 
-    ``keys`` must be the non-negative f32 :func:`rank_keys`.  Their int32 bit
-    patterns order identically to their values (IEEE-754 monotonicity on
-    non-negatives), so the k-th largest key is found by a 31-step binary
-    search on the bit pattern — compares and full-array sums only, no data
-    movement.  Entries strictly above the threshold are kept; of the entries
-    EQUAL to it, the first ``k - n_gt`` in index order are kept (prefix of
-    the running tie count), which is exactly ``lax.top_k``'s stable
-    lowest-index tie-break.  Set equality (ties included) is pinned by
-    tests/test_kernels.py.
-    """
+    ``keys`` must be the non-negative f32 :func:`rank_keys`; positions are
+    the flat index order.  See :func:`keep_mask_from_bits`."""
     bits = jax.lax.bitcast_convert_type(keys, jnp.int32)
+    pos = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    return keep_mask_from_bits(bits, pos, keys.shape[0], k, jax.lax.fori_loop)
 
-    def body(i, t):
-        cand = t | (1 << (30 - i))
-        return jnp.where(jnp.sum(bits >= cand) >= k, cand, t)
 
-    thr = jax.lax.fori_loop(0, 31, body, jnp.int32(0))
+def _static_loop(lo: int, hi: int, body, init):
+    """``fori_loop`` unrolled in Python (Pallas kernel bodies)."""
+    val = init
+    for i in range(lo, hi):
+        val = body(i, val)
+    return val
+
+
+def keep_mask_from_bits(bits, pos, t: int, k: int, loop=_static_loop):
+    """The sort-free TopK keep-mask over int32 key bit patterns.
+
+    ``bits`` holds the int32 bit patterns of the f32 rank keys (padding
+    slots set to -1, below every key), ``pos`` each slot's flat index, ``t``
+    the number of real slots.  Non-negative f32 keys order like their bit
+    patterns (IEEE-754), so the k-th largest key is found by a 31-step
+    binary search on the bit pattern.  Entries strictly above that
+    threshold are kept; of the entries EQUAL to it, the first ``k - n_gt``
+    in index order are kept — exactly ``lax.top_k``'s stable lowest-index
+    tie-break.  That prefix is found by a second binary search, on the
+    largest index bound ``p`` with ``#{eq & pos < p} <= k - n_gt``.  Both
+    searches are compares and full-array sums only: no sort, no cumsum, no
+    gather, no 64-bit integers — so the same function runs as jnp
+    (``loop=fori_loop``) and inside a Mosaic kernel (static loop).  Counts
+    are f32 sums of 0/1 (Mosaic refuses integer reductions under x64); every
+    partial sum is an integer below 2**24, so they are exact in any order.
+    Set equality, ties included, is pinned by tests/test_kernels.py.
+    """
+    if t >= 1 << 24:
+        raise ValueError(f"selection over T={t} >= 2**24 slots: f32 counts "
+                         "would be inexact")
+    i32 = jnp.int32
+
+    def count(m):
+        return jnp.sum(m.astype(jnp.float32))
+
+    def thr_step(i, thr):
+        cand = thr | jnp.left_shift(i32(1), jnp.asarray(30 - i, i32))
+        return jnp.where(count(bits >= cand) >= k, cand, thr)
+
+    thr = loop(0, 31, thr_step, i32(0))
     gt = bits > thr
     eq = bits == thr
-    n_gt = jnp.sum(gt)
-    return gt | (eq & (jnp.cumsum(eq) <= k - n_gt))
+    room = k - count(gt)
+    nb = int(t).bit_length()
+
+    def pos_step(i, p):
+        cand = p | jnp.left_shift(i32(1), jnp.asarray(nb - 1 - i, i32))
+        ok = (cand <= t) & (count(eq & (pos < cand)) <= room)
+        return jnp.where(ok, cand, p)
+
+    p = loop(0, nb, pos_step, i32(0))
+    return gt | (eq & (pos < p))
 
 
 def randseqk_window_mask(t: int, k: int, s: jax.Array) -> jax.Array:
     """Membership mask of the circular window {s, ..., s+k-1 mod T} — the
     gather-free form of RandSeqK's contiguous slice."""
-    pos = jnp.arange(t)
-    return (pos - s) % t < k
+    return window_mask(jnp.arange(t, dtype=jnp.int32), t, k, s)
+
+
+def window_mask(pos, t: int, k: int, s):
+    """``(pos - s) mod t < k`` for ``0 <= s < t``, ``k <= t`` as compares
+    only (no modulo, int32 throughout) — shared with the Pallas kernel."""
+    s = jnp.asarray(s, jnp.int32)
+    return ((pos >= s) & (pos < s + k)) | (pos < s + (k - t))
 
 
 def randseqk_dense(u: jax.Array, k: int, s: jax.Array) -> jax.Array:
@@ -140,7 +184,10 @@ def toplek_from_uniform(
     idx = topk_indices(u, k)
     vals = u[idx]  # descending by rank key, lowest-index ties first
     s2 = vals.astype(jnp.float64) ** 2 if u.dtype == jnp.float64 else vals**2
-    csum = jnp.cumsum(s2)
+    # a parallel-prefix scan, not jnp.cumsum: cumsum lowers to an O(k^2)
+    # reduce_window that the TPU compiler takes minutes over in f64 (w8a,
+    # k = 2408: ~200 s per round program, against ~2 s for this scan)
+    csum = jax.lax.associative_scan(jnp.add, s2)
     total = jnp.sum(u * u)
     safe_total = jnp.where(total > 0, total, 1.0)
     alphas = (csum / safe_total).astype(u.dtype)  # alphas[m-1] = alpha_m

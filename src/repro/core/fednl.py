@@ -56,10 +56,11 @@ class FedNLConfig:
     lam: float = 1e-3  # L2 regularization of the logistic objective
     hess0: str = "exact"  # "exact" | "zero"
     # which SYRK realizes the Hessian oracle (repro.objectives.logreg):
-    # "fused" (default) = kernels.ops.hessian_fused (Pallas on TPU, its
-    # tile-equivalent XLA program elsewhere; bit-identical to "jnp" for
-    # d <= 128); "jnp" = the single-dot_general parity reference; "pallas"
-    # forces the Pallas wrapper (interpret mode off-TPU — validation only)
+    # "fused" (default) = kernels.ops.hessian_fused (the XLA strip SYRK for
+    # f64 on every backend, Pallas for f32/bf16 on TPU — DESIGN.md §12;
+    # bit-identical to "jnp" for d <= 128); "jnp" = the single-dot_general
+    # parity reference; "pallas" forces the Pallas wrapper (compiled on TPU,
+    # interpret mode elsewhere — validation only)
     hessian: str = "fused"
     use_kernel: bool = False  # deprecated spelling of hessian="pallas"
     # line-search parameters (FedNL-LS; paper: c = 0.49, gamma = 0.5)

@@ -34,7 +34,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.api.accounting import payload_bits_fn, wire_bits_fn
 from repro.compressors import get_compressor
@@ -56,15 +55,24 @@ def shard_problem(z, mesh: Mesh, axis: str = "data"):
 
 
 def sharded_fednl_init(z, cfg: FedNLConfig, mesh: Mesh, axis: str = "data", seed: int = 0):
-    state = fednl_init(z, cfg, seed=seed)
-    h_local = jax.device_put(state.h_local, NamedSharding(mesh, P(axis, None)))
+    """Initial state with each device initializing its own clients' H_i^0
+    (`fednl_init` per shard): the shifts never leave their shard, and the
+    per-client ``lax.map`` that `fednl_init` uses above d = 128 cannot map a
+    sharded client axis."""
+    h_local = jax.jit(jax.shard_map(
+        lambda z_loc: fednl_init(z_loc, cfg, seed=seed).h_local,
+        mesh=mesh,
+        in_specs=P(axis),
+        out_specs=P(axis),
+        check_vma=False,
+    ))(z)
     rep = NamedSharding(mesh, P())
     return FedNLState(
-        x=jax.device_put(state.x, rep),
+        x=jax.device_put(jnp.zeros(z.shape[-1], dtype=z.dtype), rep),
         h_local=h_local,
-        h_global=jax.device_put(state.h_global, rep),
-        key=jax.device_put(state.key, rep),
-        round=jax.device_put(state.round, rep),
+        h_global=jax.device_put(jnp.mean(h_local, axis=0), rep),
+        key=jax.device_put(jax.random.PRNGKey(seed), rep),
+        round=jax.device_put(jnp.asarray(0), rep),
     )
 
 
@@ -143,12 +151,12 @@ def make_sharded_fednl_step(
         return (h_loc_new, x_new, h_global_new, gn, f, l, sent,
                 bits_payload, bits_wire)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(), P(), P()),
         out_specs=(P(axis), P(), P(), P(), P(), P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 

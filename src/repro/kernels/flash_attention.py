@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import TPUCompilerParams
-
 _NEG = -1e30
 
 
@@ -139,7 +137,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -17,7 +17,13 @@ hierarchy —
     MXU work and half the HBM writes, exactly the paper's upper-triangle
     trick at tile granularity;
   * diag(h) is fused into the right operand load (one multiply in VMEM, no
-    materialized (n, d) scaled copy in HBM).
+    materialized (n, d) scaled copy in HBM); h travels as an (n, 1) column
+    so its block is 2-D (a 1-D (block_n,) block does not match Mosaic's
+    vector layout).
+
+Mosaic has no f64: the compiled kernel takes f32/bf16 operands (bf16
+accumulates and returns f32) and raises a TypeError naming any other dtype.
+Interpret mode takes any float.
 
 The jit'd wrapper (ops.hessian_syrk) pads (n, d) to tile multiples, mirrors
 the strict upper tiles after the call, and slices the padding away.
@@ -32,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import TPUCompilerParams
+from repro.kernels import check_mosaic_dtype
 
 
 def _syrk_kernel(z_i_ref, z_j_ref, h_ref, o_ref, *, grid_k: int):
@@ -50,12 +56,13 @@ def _syrk_kernel(z_i_ref, z_j_ref, h_ref, o_ref, *, grid_k: int):
     def _compute():
         zi = z_i_ref[...]  # (bk, bd) chunk of Z for row-tile i
         zj = z_j_ref[...]  # (bk, bd) chunk of Z for col-tile j
-        hh = h_ref[...]  # (bk,) sample weights
-        zj_scaled = zj * hh[:, None]
+        hh = h_ref[...]  # (bk, 1) sample weights
+        zj_scaled = zj * hh
         acc = jax.lax.dot_general(
             zi,
             zj_scaled,
             dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,  # full f32 passes on the MXU
             preferred_element_type=o_ref.dtype,
         )
         o_ref[...] += acc
@@ -75,6 +82,9 @@ def hessian_syrk_pallas(
     """
     n, d = z.shape
     assert n % block_n == 0 and d % block_d == 0, (n, d, block_n, block_d)
+    if not interpret:
+        check_mosaic_dtype("hessian_syrk_pallas", z, h)
+    out_dtype = jnp.float32 if z.dtype == jnp.bfloat16 else z.dtype
     grid = (d // block_d, d // block_d, n // block_n)
     kernel = functools.partial(_syrk_kernel, grid_k=grid[2])
     return pl.pallas_call(
@@ -83,12 +93,12 @@ def hessian_syrk_pallas(
         in_specs=[
             pl.BlockSpec((block_n, block_d), lambda i, j, k: (k, i)),
             pl.BlockSpec((block_n, block_d), lambda i, j, k: (k, j)),
-            pl.BlockSpec((block_n,), lambda i, j, k: (k,)),
+            pl.BlockSpec((block_n, 1), lambda i, j, k: (k, jnp.int32(0))),
         ],
         out_specs=pl.BlockSpec((block_d, block_d), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((d, d), z.dtype),
-        compiler_params=TPUCompilerParams(
+        out_shape=jax.ShapeDtypeStruct((d, d), out_dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(z, z, h)
+    )(z, z, h.reshape(n, 1))

@@ -1,30 +1,30 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Jit'd public wrappers around the Pallas kernels, and the one place that
+routes the round hot path between them and their XLA formulations.
 
-On the CPU container the Pallas programs execute in interpret mode (the
-kernel body runs as traced jnp — bit-accurate vs the TPU semantics for these
-ops); on a TPU backend ``interpret=False`` compiles through Mosaic.
+The route (DESIGN.md §12) is keyed on the operand dtype and the requested
+implementation — never on ``jax.default_backend()`` alone:
 
-Two wrapper-layer rules keep the jit caches honest:
+* ``hessian="fused"`` / the fused selection: **f64 operands** (the default
+  FedNL round) run the XLA strip SYRK (:func:`hessian_syrk_xla`) and the
+  masked jnp selection (`repro.compressors.select`) on every backend, so
+  the CPU tests cover the exact formulation the chip runs.  f32/bf16
+  operands run the Mosaic-compiled Pallas kernels where the computation
+  targets a TPU, the same XLA/jnp formulations elsewhere.
+* ``hessian="pallas"`` (:func:`hessian_syrk`) always runs the Pallas
+  kernel: compiled on a TPU — where an f64 operand raises a TypeError
+  naming the dtype — and in interpret mode off-TPU or where a caller passes
+  ``interpret=True``.  Interpret mode is never a TPU default.
 
-* **Interpret resolution happens eagerly, before jit.**  The public wrappers
-  resolve ``interpret=None`` -> ``jax.default_backend() != "tpu"`` at call
-  time and pass the resolved bool through the *static* ``interpret``
-  argument.  Resolving it inside the jitted body would bake the choice into
-  the cache entry under the ``interpret=None`` key: the first call pins the
-  backend decision for every later call (wrong if the default backend
-  changes, or differs across processes sharing a compilation cache).
-* **No per-call mask construction.**  The strict-upper/diagonal block masks
-  used by the SYRK mirror epilogue are built ONCE per (dp, block_d) with
-  numpy at trace time (`_mirror_masks`, lru_cached) and embedded in the
-  compiled program as constants — the hot path carries no O(d^2) mask
-  rebuild.
+The target platform is that of a concrete operand's device; under a trace
+(no device yet) it is the default device (``jax.default_device``), else the
+default backend.  It is resolved eagerly, outside jit, and passed through
+static arguments, so interpret and Mosaic variants occupy distinct cache
+entries.
 
-The selection wrappers (``select_topk`` / ``select_toplek`` /
-``select_randseqk``) route the compressor hot path: on TPU they invoke the
-fused Pallas selection kernel (`repro.kernels.compressor_select`); elsewhere
-they run the canonical jnp selection primitives
-(`repro.compressors.select`), which the kernel is pinned against bit-for-bit
-(same f32 magnitude keys, same lowest-index tie-break — DESIGN.md §12).
+The strict-upper/diagonal block masks used by the SYRK mirror epilogue are
+built ONCE per (dp, block_d) with numpy at trace time (`_mirror_masks`,
+lru_cached) and embedded in the compiled program as constants — the hot
+path carries no O(d^2) mask rebuild.
 """
 
 from __future__ import annotations
@@ -35,22 +35,35 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import MOSAIC_DTYPES
 from repro.kernels.hessian_syrk import hessian_syrk_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 
 
-def _should_interpret() -> bool:
-    """True when the Pallas kernels must run in interpret mode (non-TPU).
+def target_platform(*arrays) -> str:
+    """Platform the computation on ``arrays`` targets (module docstring)."""
+    for a in arrays:
+        if isinstance(a, jax.Array) and not isinstance(a, jax.core.Tracer):
+            return next(iter(a.devices())).platform
+    dev = jax.config.jax_default_device
+    if dev is not None:
+        return dev if isinstance(dev, str) else dev.platform
+    return jax.default_backend()
 
-    Call this EAGERLY (outside jit) and pass the result through a static
-    argument — see the module docstring.
-    """
-    return jax.default_backend() != "tpu"
+
+def resolve_interpret(interpret: bool | None, *arrays) -> bool:
+    """Eagerly resolve an ``interpret=None`` default: interpret off-TPU."""
+    if interpret is None:
+        return target_platform(*arrays) != "tpu"
+    return bool(interpret)
 
 
-def resolve_interpret(interpret: bool | None) -> bool:
-    """Eagerly resolve an ``interpret=None`` default to the backend choice."""
-    return _should_interpret() if interpret is None else bool(interpret)
+def runs_mosaic(*arrays) -> bool:
+    """True when the fused hot path takes the compiled Pallas kernel:
+    f32/bf16 operands on a TPU.  f64 never does (module docstring)."""
+    return all(
+        jnp.dtype(a.dtype) in MOSAIC_DTYPES for a in arrays
+    ) and target_platform(*arrays) == "tpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -113,11 +126,13 @@ def hessian_syrk(
     Zero-pads to tile multiples (zero-weight rows are exact no-ops; padded
     feature columns are sliced away), mirrors the strict-upper tiles.
 
-    ``interpret=None`` resolves to the current default backend *at call
-    time* (not at trace time — the resolved flag is a static jit argument,
-    so interpret and Mosaic variants occupy distinct cache entries).
+    ``interpret=None`` resolves to the target platform *at call time*
+    (module docstring): compiled on a TPU, where a float64 operand raises a
+    TypeError naming the dtype; interpret mode elsewhere.
     """
-    return _hessian_syrk_jit(z, h, block_d, block_n, resolve_interpret(interpret))
+    return _hessian_syrk_jit(
+        z, h, block_d, block_n, resolve_interpret(interpret, z, h)
+    )
 
 
 def _syrk_blockform(z: jax.Array, h: jax.Array, block_d: int) -> jax.Array:
@@ -182,20 +197,20 @@ def hessian_fused(
     *,
     block_d: int = 128,
     block_n: int = 128,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """The default fused-Hessian entry point of the round hot path.
 
-    Routes eagerly (host-side, never inside a trace) on the resolved
-    backend: Mosaic-compiled Pallas SYRK on TPU, the tile-equivalent XLA
-    program (:func:`hessian_syrk_xla`) everywhere else — interpret-mode
-    Pallas is a validation path, ~9x slower than XLA on CPU, so it is never
-    the default hot path (`repro.objectives.logreg` routes here with
-    ``hessian="fused"``; ``hessian="pallas"`` forces the wrapper above).
+    Routes eagerly (host-side) on dtype and target (module docstring): the
+    Mosaic-compiled Pallas SYRK for f32/bf16 operands on a TPU, the
+    tile-equivalent XLA program (:func:`hessian_syrk_xla`) otherwise — in
+    particular for every f64 round, on every backend.  Interpret-mode
+    Pallas is a validation path, never taken here (`repro.objectives.logreg`
+    routes here with ``hessian="fused"``; ``hessian="pallas"`` forces the
+    wrapper above).
     """
-    if resolve_interpret(interpret):
-        return hessian_syrk_xla(z, h, block_d=block_d)
-    return hessian_syrk(z, h, block_d=block_d, block_n=block_n, interpret=False)
+    if runs_mosaic(z, h):
+        return hessian_syrk(z, h, block_d=block_d, block_n=block_n, interpret=False)
+    return hessian_syrk_xla(z, h, block_d=block_d)
 
 
 def hessian_syrk_packed(
@@ -203,7 +218,6 @@ def hessian_syrk_packed(
     h: jax.Array,
     *,
     block_d: int = 128,
-    interpret: bool | None = None,
 ) -> jax.Array:
     """``pack_triu(Z^T diag(h) Z)`` without materializing the mirrored matrix.
 
@@ -215,94 +229,83 @@ def hessian_syrk_packed(
     only add +0.0 to each before ``pack_triu`` re-extracts it.  Bit-identical
     to ``pack_triu(hessian_fused(z, h))`` (+0.0 can only flip a -0.0 to +0.0,
     and callers add the regularization term packed, replaying the historical
-    ``hess + lam*eye`` per-element op order exactly).
+    ``hess + lam*eye`` per-element op order exactly).  Routed like
+    :func:`hessian_fused`.
     """
     from repro.linalg import pack_triu
 
-    if resolve_interpret(interpret):
-        _, d = z.shape
-        if d <= block_d:
-            return pack_triu(z.T @ (h[:, None] * z))
-        return pack_triu(_syrk_blockform(z, h, block_d))
-    return pack_triu(hessian_syrk(z, h, block_d=block_d, interpret=False))
+    if runs_mosaic(z, h):
+        return pack_triu(hessian_syrk(z, h, block_d=block_d, interpret=False))
+    _, d = z.shape
+    if d <= block_d:
+        return pack_triu(z.T @ (h[:, None] * z))
+    return pack_triu(_syrk_blockform(z, h, block_d))
 
 
 # ---------------------------------------------------------------------------
 # fused compressor selection (TopK / TopLEK ranking, RandSeqK window)
 # ---------------------------------------------------------------------------
 
-def select_topk(u: jax.Array, k: int, *, interpret: bool | None = None,
-                fused: bool = False):
-    """Fused TopK selection: ``(u_hat, sent)`` in one pass over u.
+def select_topk(u: jax.Array, k: int, *, fused: bool = False):
+    """TopK selection: ``(u_hat, sent)``.
 
     Selection contract (DESIGN.md §12): rank by f32(|u|), ties broken toward
-    the lowest packed index — pinned in `repro.compressors.select`.  On TPU
-    this runs the Pallas selection kernel; elsewhere the canonical jnp
-    primitives (bit-identical output by the pinned contract).
+    the lowest packed index — pinned in `repro.compressors.select`.  f32/bf16
+    payloads on a TPU run the Pallas selection kernel; everything else (the
+    f64 round on every backend) the jnp primitives — bit-identical output by
+    the pinned contract.
 
-    ``fused=True`` picks the sort-free threshold-mask formulation on CPU —
-    literally the algorithm the Pallas kernel runs.  It is faster inside the
-    fused round's per-client ``lax.map`` (no batched-sort layout, measured
-    ~1.6x on w8a) and slower under ``vmap``, so the reference round keeps
-    the sorted form; the outputs are bit-identical either way.
+    ``fused=True`` picks the sort-free threshold-mask formulation — the
+    algorithm the Pallas kernel runs, and the one the fused round maps per
+    client; the reference round keeps the sorted form.
     """
-    if resolve_interpret(interpret):
-        from repro.compressors import select as csel
+    if runs_mosaic(u):
+        from repro.kernels.compressor_select import select_topk_pallas
 
-        if fused:
-            return csel.topk_dense_masked(u, k), jnp.asarray(k)
-        return csel.topk_dense(u, k), jnp.asarray(k)
-    from repro.kernels.compressor_select import select_topk_pallas
+        u_hat, sent = select_topk_pallas(u, k, interpret=False)
+        return u_hat, sent[0].astype(jnp.asarray(k).dtype)
+    from repro.compressors import select as csel
 
-    u_hat, sent = select_topk_pallas(u, k, interpret=False)
-    return u_hat, sent[0].astype(jnp.asarray(k).dtype)
+    if fused:
+        return csel.topk_dense_masked(u, k), jnp.asarray(k)
+    return csel.topk_dense(u, k), jnp.asarray(k)
 
 
-def select_toplek(key: jax.Array, u: jax.Array, k: int, *,
-                  interpret: bool | None = None, fused: bool = False):
-    """Fused TopLEK: TopK ranking + the Algorithm-4 adaptive prefix.
+def select_toplek(key: jax.Array, u: jax.Array, k: int, *, fused: bool = False):
+    """TopLEK: TopK ranking + the Algorithm-4 adaptive prefix, as jnp on
+    every backend (the prefix needs the ranked ORDER — a sort — so it has
+    no Pallas kernel and no sort-free form; ``fused`` is accepted for
+    call-site symmetry and ignored).
 
-    The Bernoulli draw stays outside the kernel as ``uniform(key)`` in u's
-    dtype — exactly what ``jax.random.bernoulli(key, p)`` lowers to — so
-    fused and unfused paths consume the PRNG stream identically.
-
-    ``fused`` is accepted for call-site symmetry with the other selectors
-    and ignored: the adaptive prefix needs the ranked ORDER (cumulative
-    energy in descending-key order), which the sort-free threshold mask
-    cannot provide, so both rounds share the one sorted body.
+    The Bernoulli draw is ``uniform(key)`` in u's dtype — exactly what
+    ``jax.random.bernoulli(key, p)`` lowers to.
     """
     del fused
     from repro.compressors import select as csel
 
-    unif = csel.toplek_uniform(key, u.dtype)
-    if resolve_interpret(interpret):
-        return csel.toplek_from_uniform(u, k, unif)
-    from repro.kernels.compressor_select import select_toplek_pallas
-
-    u_hat, sent = select_toplek_pallas(u, k, unif, interpret=False)
-    return u_hat, sent[0].astype(jnp.asarray(k).dtype)
+    return csel.toplek_from_uniform(u, k, csel.toplek_uniform(key, u.dtype))
 
 
-def select_randseqk(key: jax.Array, u: jax.Array, k: int, *,
-                    interpret: bool | None = None, fused: bool = False):
-    """Fused RandSeqK (Appendix C): one PRG draw, contiguous window keep.
+def select_randseqk(key: jax.Array, u: jax.Array, k: int, *, fused: bool = False):
+    """RandSeqK (Appendix C): one PRG draw, contiguous window keep.
 
     ``fused=True`` uses the gather-free circular-window mask (the Pallas
     kernel's formulation) instead of roll + prefix slice; values are pure
-    copies either way, so the outputs are bit-identical.
+    copies either way, so the outputs are bit-identical.  Routed like
+    :func:`select_topk`.
     """
     t = u.shape[0]
     s = jax.random.randint(key, (), 0, t)
-    if resolve_interpret(interpret):
-        from repro.compressors import select as csel
+    if runs_mosaic(u):
+        from repro.kernels.compressor_select import select_randseqk_pallas
 
-        if fused:
-            return csel.randseqk_dense_masked(u, k, s), jnp.asarray(k)
-        return csel.randseqk_dense(u, k, s), jnp.asarray(k)
-    from repro.kernels.compressor_select import select_randseqk_pallas
+        u_hat, sent = select_randseqk_pallas(u, k, s, interpret=False)
+        return u_hat, sent[0].astype(jnp.asarray(k).dtype)
+    from repro.compressors import select as csel
 
-    u_hat, sent = select_randseqk_pallas(u, k, s, interpret=False)
-    return u_hat, sent[0].astype(jnp.asarray(k).dtype)
+    if fused:
+        return csel.randseqk_dense_masked(u, k, s), jnp.asarray(k)
+    return csel.randseqk_dense(u, k, s), jnp.asarray(k)
 
 
 # ---------------------------------------------------------------------------
@@ -356,5 +359,5 @@ def flash_attention(
     """
     return _flash_attention_jit(
         q, k, v, causal, window, scale, block_q, block_k,
-        resolve_interpret(interpret),
+        resolve_interpret(interpret, q, k, v),
     )

@@ -9,7 +9,8 @@
         --algo fednl-pp --tau 3 --drop-prob 0.2 --rounds 60 --check
 
 The master process binds a localhost socket, spawns one OS process per client
-(``multiprocessing`` spawn context: each child gets a fresh JAX runtime), and
+(``multiprocessing`` spawn context: each child gets a fresh JAX runtime on
+the host CPU — the master keeps any accelerator), and
 runs the star event loop of ``repro.comm.star`` (full participation) or
 ``repro.comm.star_pp`` (FedNL-PP: only the sampled tau clients receive or do
 any work each round).  Data distribution follows the paper's experiment
@@ -31,6 +32,7 @@ import os
 import threading
 
 from repro.core.fednl import FedNLConfig
+from repro.runtime import configure_jax
 
 
 def _build_problem(dataset: str, shape, seed: int):
@@ -55,9 +57,7 @@ def _client_entry(
     data_seed: int | None = None,
 ) -> None:
     """Client process: build shard, dial the master, serve rounds."""
-    import jax
-
-    jax.config.update("jax_enable_x64", True)  # FedNL is FP64 end-to-end
+    configure_jax()  # FedNL is FP64 end-to-end
     from repro.comm.transport import connect_to_master
 
     z = _build_problem(dataset, shape, seed if data_seed is None else data_seed)
@@ -109,9 +109,7 @@ def _aggregator_entry(
     connection, so a tree tears down leaves-first and the root's
     ``ClientCluster.close()`` never abandons a grandchild.
     """
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
+    configure_jax()
     from repro.comm.topology import build_aggregator
     from repro.comm.transport import TCPMaster, connect_to_master
 
@@ -182,31 +180,38 @@ _SPAWN_ENV_LOCK = threading.Lock()
 
 def _spawn_procs(targets) -> list:
     """Start one spawn-context process per ``(target, args, daemon)`` triple
-    with ``src/`` on the children's PYTHONPATH (mutate-spawn-restore under
-    the shared lock).  Children capture os.environ at start(), so nested
-    spawns — aggregator processes spawning their own subtrees — inherit the
-    path without re-mutating anything.  ``daemon`` must be False for any
-    child that spawns processes of its own (aggregators)."""
+    with ``src/`` on the children's PYTHONPATH and ``JAX_PLATFORMS=cpu``
+    (mutate-spawn-restore under the shared lock).
+
+    The children model remote clients and aggregators, so they run JAX on
+    the host CPU: an accelerator belongs to one process at a time, and the
+    master — which has already touched JAX — holds it.  Children capture
+    os.environ at start(), so nested spawns — aggregator processes spawning
+    their own subtrees — inherit both settings without re-mutating
+    anything.  ``daemon`` must be False for any child that spawns processes
+    of its own (aggregators)."""
     ctx = mp.get_context("spawn")
     src_dir = os.path.dirname(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
     procs: list = []
     with _SPAWN_ENV_LOCK:
-        old_pp = os.environ.get("PYTHONPATH")
+        old = {k: os.environ.get(k) for k in ("PYTHONPATH", "JAX_PLATFORMS")}
         os.environ["PYTHONPATH"] = src_dir + (
-            os.pathsep + old_pp if old_pp else ""
+            os.pathsep + old["PYTHONPATH"] if old["PYTHONPATH"] else ""
         )
+        os.environ["JAX_PLATFORMS"] = "cpu"
         try:
             for target, args, daemon in targets:
                 p = ctx.Process(target=target, args=args, daemon=daemon)
                 p.start()
                 procs.append(p)
         finally:
-            if old_pp is None:
-                os.environ.pop("PYTHONPATH", None)
-            else:
-                os.environ["PYTHONPATH"] = old_pp
+            for k, v in old.items():
+                if v is None:
+                    os.environ.pop(k, None)
+                else:
+                    os.environ[k] = v
     return procs
 
 # every live (not yet closed) cluster, so a serving engine — or a test —
@@ -248,9 +253,7 @@ class ClientCluster:
         data_seed: int | None = None,
         cfg: FedNLConfig | None = None,
     ):
-        import jax
-
-        jax.config.update("jax_enable_x64", True)
+        configure_jax()
         from repro.comm.transport import TCPMaster
 
         # dims only — the master never holds the training data; workers
@@ -386,9 +389,7 @@ class TreeClientCluster(ClientCluster):
         data_seed: int | None = None,
         cfg: FedNLConfig | None = None,
     ):
-        import jax
-
-        jax.config.update("jax_enable_x64", True)
+        configure_jax()
         from repro.api.spec import DataSpec
         from repro.comm.transport import TCPMaster
 

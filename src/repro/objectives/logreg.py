@@ -90,8 +90,9 @@ def logreg_oracles(
     """Fused (f, grad, hess) sharing one margin/sigmoid computation (§5.7).
 
     hessian: which SYRK realizes Z^T diag(h) Z (DESIGN.md §12):
-      "fused"   (default) repro.kernels.ops.hessian_fused — the Pallas SYRK
-                kernel on TPU, its tile-equivalent XLA program elsewhere.
+      "fused"   (default) repro.kernels.ops.hessian_fused — the
+                tile-equivalent XLA program for f64 on every backend (the
+                Pallas SYRK only for f32/bf16 on TPU).
                 For d <= 128 (one tile) the XLA program is literally the
                 "jnp" expression, so the default is bit-identical to the
                 historical path there; for larger d the blocked accumulation

@@ -12,8 +12,10 @@ post-SPMD, per-device module).  Collective bytes are NOT in cost_analysis:
 result-shape bytes of every all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute (sync or async-start form).
 
-Target hardware (TPU v5e): 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Target hardware (TPU v5e, Google Cloud documentation "TPU v5e"):
+197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link ICI.  Peaks are
+looked up by ``device_kind`` (:func:`machine_for`); a device that is not in
+the table is an error, not a default.
 """
 
 from __future__ import annotations
@@ -30,11 +32,10 @@ ICI_BW = 50e9  # bytes / s / link
 
 @dataclasses.dataclass(frozen=True)
 class Machine:
-    """Roofline ceilings of one device.  The module-level constants above are
-    the historical TPU-v5e values; pass an explicit Machine to `analyze` (or
-    build one with :func:`measure_cpu_machine`) to gate benchmarks run on a
-    different host — e.g. the CPU container that produces BENCH_kernels.json.
-    """
+    """Roofline ceilings of one device.  `analyze` takes one explicitly:
+    :func:`machine_for` looks a chip up by ``device_kind``, and
+    :func:`measure_cpu_machine` measures a CPU host (whose numbers are
+    never device metrics)."""
 
     name: str
     peak_flops: float  # flop / s
@@ -43,6 +44,20 @@ class Machine:
 
 
 TPU_V5E = Machine("tpu-v5e", PEAK_FLOPS, HBM_BW, ICI_BW)
+
+# keyed by jax.Device.device_kind as the TPU runtime reports it
+MACHINES = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def machine_for(device_kind: str) -> Machine:
+    """The roofline ceilings of a device, by its ``device_kind``."""
+    try:
+        return MACHINES[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(MACHINES)}"
+        ) from None
 
 
 def measure_cpu_machine(*, n: int = 1024, dtype=None, reps: int = 3) -> Machine:
@@ -82,10 +97,7 @@ def hlo_cost(fn, *args) -> dict[str, float]:
     cost_analysis of the compiled module (no execution)."""
     import jax
 
-    compiled = jax.jit(fn).lower(*args).compile()
-    costs = compiled.cost_analysis()
-    if isinstance(costs, list):  # older jax returns [dict]
-        costs = costs[0]
+    costs = jax.jit(fn).lower(*args).compile().cost_analysis()
     return {
         "flops": float(costs.get("flops", 0.0)),
         "bytes": float(costs.get("bytes accessed", 0.0)),
@@ -154,13 +166,11 @@ class Roofline:
 
 
 def analyze(
-    compiled, *, chips: int, model_flops_global: float,
-    machine: Machine = TPU_V5E,
+    compiled, *, chips: int, model_flops_global: float, machine: Machine,
 ) -> Roofline:
-    """Build the three-term roofline from a compiled executable."""
+    """Build the three-term roofline from a compiled executable against
+    ``machine``'s peaks (:func:`machine_for` on the device's kind)."""
     costs = compiled.cost_analysis()
-    if isinstance(costs, list):  # older jax returns [dict]
-        costs = costs[0]
     flops = float(costs.get("flops", 0.0))
     hbm = float(costs.get("bytes accessed", 0.0))
     colls = collective_bytes(compiled.as_text())

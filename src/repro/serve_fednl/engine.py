@@ -50,6 +50,7 @@ from repro.serve_fednl.scheduler import (
     serve_group_key,
     serve_lane,
 )
+from repro.runtime import configure_jax
 from repro.serve_fednl.spill import SpillManager
 from repro.serve_fednl.tenant import (
     CANCELLED,
@@ -105,7 +106,7 @@ class FedNLServer:
         self.config = config or ServeConfig()
         if self.config.max_resident < 1:
             raise ValueError("max_resident must be >= 1")
-        jax.config.update("jax_enable_x64", True)
+        configure_jax()
         self._lock = threading.RLock()
         self._queue = FairShareQueue(
             self.config.priorities, quantum=self.config.quantum

@@ -232,6 +232,16 @@ def test_star_roofline_dominance():
     assert r2["dominant"] == "compute"
 
 
+def test_machine_for_device_kind():
+    """Roofline peaks come from a table keyed by device_kind; a device that
+    is not in it is an error, never a v5e default."""
+    from repro.roofline import TPU_V5E, machine_for
+
+    assert machine_for("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        machine_for("cpu")
+
+
 # ---------------------------------------------------------------------------
 # TCP localhost, real client processes (acceptance criterion)
 # ---------------------------------------------------------------------------

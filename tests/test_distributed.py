@@ -109,3 +109,40 @@ def test_multidevice_integration_subprocess(agg):
     )
     assert out.returncode == 0, out.stderr[-2000:]
     assert "OK" in out.stdout
+
+
+def test_sharded_init_maps_clients_per_shard_subprocess():
+    """Above d = 128 `fednl_init` maps clients with lax.map, which cannot map
+    a sharded client axis: the sharded init runs it per shard, and the
+    shifts it produces sit on every device and equal the single-node ones."""
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import jax
+        jax.config.update("jax_enable_x64", True)
+        import numpy as np
+        from repro.api import DataSpec
+        from repro.core import FedNLConfig
+        from repro.core.fednl import FUSED_VMAP_MAX_D, fednl_init
+        from repro.distributed import (
+            make_sharded_fednl_round, shard_problem, sharded_fednl_init)
+
+        d = FUSED_VMAP_MAX_D + 22
+        z = DataSpec(shape=(d, 8, 40), seed=1).build()
+        mesh = jax.make_mesh((4,), ("data",))
+        zs = shard_problem(z, mesh)
+        cfg = FedNLConfig(compressor="topk")
+        st = sharded_fednl_init(zs, cfg, mesh, seed=0)
+        assert len(st.h_local.sharding.device_set) == 4
+        ref = fednl_init(z, cfg, seed=0)
+        np.testing.assert_array_equal(np.asarray(st.h_local), np.asarray(ref.h_local))
+        st, m = jax.jit(make_sharded_fednl_round(zs, cfg, mesh))(st)
+        print("OK", float(m["grad_norm"]))
+    """)
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, capture_output=True, text=True,
+        timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "OK" in out.stdout

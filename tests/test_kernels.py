@@ -21,12 +21,11 @@ import numpy as np
 import pytest
 
 from repro.compressors import select as csel
-from repro.compressors.core import randseqk, topk, toplek
+from repro.compressors.core import randseqk, topk
 from repro.kernels import ops
 from repro.kernels.compressor_select import (
     select_randseqk_pallas,
     select_topk_pallas,
-    select_toplek_pallas,
 )
 from repro.kernels.ref import flash_attention_ref, hessian_syrk_ref
 
@@ -252,13 +251,6 @@ def test_select_kernels_interpret_parity(t, k, dtype):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert int(sent[0]) == k
 
-    tk = jax.random.fold_in(key, 2)
-    want, kept = toplek(tk, u, k)
-    unif = csel.toplek_uniform(tk, u.dtype)
-    got, sent = select_toplek_pallas(u, k, unif, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert int(sent[0]) == int(kept)
-
 
 def test_toplek_uniform_replays_bernoulli():
     """The hoisted uniform draw consumes the PRNG stream exactly as
@@ -369,3 +361,70 @@ def test_flash_matches_models_chunked_attention():
     np.testing.assert_allclose(
         np.asarray(kern_out), np.asarray(jnp_out), atol=2e-5
     )
+
+
+# ---------------------------------------------------------------------------
+# the route (DESIGN.md §12): dtype and impl pick the formulation
+# ---------------------------------------------------------------------------
+
+def _tiny_round_jaxpr(dtype, hessian="fused", d=24):
+    from repro.core.fednl import FedNLConfig, fednl_init, make_fednl_round
+
+    z = jax.random.normal(jax.random.PRNGKey(0), (4, 20, d), dtype=dtype)
+    cfg = FedNLConfig(compressor="topk", hessian=hessian)
+    state = jax.eval_shape(lambda: fednl_init(z, cfg))
+    return str(jax.make_jaxpr(make_fednl_round(z, cfg))(state))
+
+
+@pytest.mark.parametrize("d", [24, 150])
+def test_f64_fused_round_has_no_pallas_call(monkeypatch, d):
+    """Even when the round targets a TPU, the f64 fused round traces no
+    Pallas call (Mosaic has no f64); an f32 round there does."""
+    monkeypatch.setattr(ops, "target_platform", lambda *a: "tpu")
+    assert "pallas_call" not in _tiny_round_jaxpr(jnp.float64, d=d)
+    assert "pallas_call" in _tiny_round_jaxpr(jnp.float32, d=d)
+
+
+def test_f64_route_is_the_same_on_every_backend(monkeypatch):
+    """The f64 fused round is one program whether it targets a TPU or the
+    CPU: the CPU tests cover the formulation the chip runs."""
+    monkeypatch.setattr(ops, "target_platform", lambda *a: "tpu")
+    on_tpu = _tiny_round_jaxpr(jnp.float64, d=150)
+    monkeypatch.setattr(ops, "target_platform", lambda *a: "cpu")
+    assert _tiny_round_jaxpr(jnp.float64, d=150) == on_tpu
+
+
+@pytest.mark.parametrize("kernel", ["syrk", "topk", "randseqk"])
+def test_compiled_pallas_at_f64_names_the_dtype(kernel):
+    """A Pallas wrapper called at f64 with interpret=False raises a
+    TypeError naming the dtype, not a Mosaic lowering error."""
+    u = jnp.ones((300,), jnp.float64)
+    calls = {
+        "syrk": lambda: ops.hessian_syrk(jnp.ones((8, 8), jnp.float64),
+                                         jnp.ones((8,), jnp.float64),
+                                         interpret=False),
+        "topk": lambda: select_topk_pallas(u, 4, interpret=False),
+        "randseqk": lambda: select_randseqk_pallas(u, 4, jnp.int32(3),
+                                                   interpret=False),
+    }
+    with pytest.raises(TypeError, match="float64"):
+        calls[kernel]()
+
+
+def test_explicit_pallas_on_tpu_never_interprets(monkeypatch):
+    """hessian='pallas' on a TPU target compiles (no interpret mode): at f64
+    that is the dtype error, raised while the round is traced."""
+    monkeypatch.setattr(ops, "target_platform", lambda *a: "tpu")
+    assert ops.resolve_interpret(None) is False
+    with pytest.raises(TypeError, match="float64"):
+        _tiny_round_jaxpr(jnp.float64, hessian="pallas")
+
+
+def test_target_platform_follows_default_device():
+    """A CPU reference inside a TPU process (jax.default_device(cpu)) is
+    routed as CPU: the target is the data's device, then the default
+    device, then the default backend."""
+    cpu = jax.devices("cpu")[0]
+    with jax.default_device(cpu):
+        assert ops.target_platform() == "cpu"
+    assert ops.target_platform(jnp.ones(3)) == jax.devices()[0].platform
